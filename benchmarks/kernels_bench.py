@@ -74,9 +74,9 @@ def run(quick: bool = False):
     rows.append(("admit_xla_p15", us, f"{P/us:.0f}pkt/us"))
     if not quick:
         f_adm_p = jax.jit(lambda k, s, w, c: ops.admission_admit(
-            k, s, w, c, num_keys=NKEY))
+            k, s, w, c, num_keys=NKEY, interpret=True))
         us = _bench(f_adm_p, akey, asz, awant, acap, iters=2)
-        rows.append(("admit_pallas_p15", us,
+        rows.append(("admit_pallas_interpret_p15", us,
                      "interpret-mode (dispatch cost only)"))
 
     # flash attention oracle vs naive jnp (CPU walltime, small shape)

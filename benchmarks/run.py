@@ -123,6 +123,16 @@ def _check(mods: list[str], tol: float, repeat: int) -> int:
     return 0
 
 
+def _use_compile_cache() -> None:
+    """JAX's persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says; without it, to a fixed directory of the checkout, so later runs
+    of this checkout find it."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
@@ -138,6 +148,7 @@ def main() -> None:
     ap.add_argument("--repeat", type=int, default=3,
                     help="quick runs per module for the --check minimum")
     args = ap.parse_args()
+    _use_compile_cache()
     mods = args.only.split(",") if args.only else MODULES
     if args.check:
         sys.exit(_check(mods, args.tol, args.repeat))

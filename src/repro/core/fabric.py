@@ -1331,7 +1331,6 @@ def _simulate_sharded_jit(j, cfg: FabricConfig, num_slices: int,
                           per_packet_mp: bool, num_flows: int,
                           num_shards: int, mesh,
                           telemetry: TelemetryConfig | None = None):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     def body(jl):
@@ -1365,8 +1364,8 @@ def _simulate_sharded_jit(j, cfg: FabricConfig, num_slices: int,
     if telemetry is not None:
         # counter rows are psum-reconciled inside the step -> replicated
         out_specs.update({k: PS() for k in TELE_KEYS})
-    return shard_map(body, mesh=mesh, in_specs=(in_specs,),
-                     out_specs=out_specs, check_rep=False)(j)
+    return jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
+                         out_specs=out_specs, check_vma=False)(j)
 
 
 def _check_impls(cfg: FabricConfig):

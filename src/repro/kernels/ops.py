@@ -1,8 +1,10 @@
 """Public jit'd wrappers over the Pallas kernels with jnp-oracle dispatch.
 
-``impl="pallas"`` runs the TPU kernels (``interpret=True`` executes the kernel
-body on CPU — the validation mode used everywhere in this container);
-``impl="ref"`` runs the pure-jnp oracles from :mod:`repro.kernels.ref`.
+``impl="pallas"`` runs the TPU kernels; the fabric kernels
+(``time_flow_lookup``, ``admission_admit``) run compiled unless the caller
+passes ``interpret=True``, which executes the kernel body on the CPU (the
+validation mode of the CPU test suite). ``impl="ref"`` runs the pure-jnp
+oracles from :mod:`repro.kernels.ref`.
 The model stack uses the oracles for SPMD dry-runs (Mosaic kernels cannot
 lower on the CPU backend) and the kernels on real TPU deployments.
 """

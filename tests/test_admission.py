@@ -56,7 +56,8 @@ def test_admission_kernel_matches_oracle_and_xla(P, nk):
     size = jnp.asarray(rng.integers(0, 2000, P), jnp.int32)
     want = jnp.asarray(rng.random(P) < 0.7)
     cap = jnp.asarray(rng.integers(0, 6000, nk), jnp.int32)
-    a_k, u_k = ops.admission_admit(key, size, want, cap, num_keys=nk)
+    a_k, u_k = ops.admission_admit(key, size, want, cap, num_keys=nk,
+                                   interpret=True)
     a_r, u_r = ops.admission_admit(key, size, want, cap, num_keys=nk,
                                    impl="ref")
     a_x, u_x = _group_admit(key, size, want, cap, nk)
@@ -74,7 +75,8 @@ def test_admission_kernel_fifo_semantics():
     size = jnp.asarray([60, 50, 30, 10, 60], jnp.int32)
     want = jnp.asarray([True, True, True, True, True])
     cap = jnp.asarray([100, 100], jnp.int32)
-    adm, used = ops.admission_admit(key, size, want, cap, num_keys=2, bp=2)
+    adm, used = ops.admission_admit(key, size, want, cap, num_keys=2, bp=2,
+                                     interpret=True)
     # group 0: 60 in, 30 in, 10 in (100 exactly); group 1: 50 in, 60 out
     np.testing.assert_array_equal(np.asarray(adm),
                                   [True, True, True, True, False])
@@ -89,7 +91,7 @@ def test_admission_kernel_interpret_smoke():
     rng = np.random.default_rng(0)
     P, nk = 1111, 77
     f = jax.jit(lambda k, s, w, c: ops.admission_admit(
-        k, s, w, c, num_keys=nk, bp=128))
+        k, s, w, c, num_keys=nk, bp=128, interpret=True))
     adm, used = f(jnp.asarray(rng.integers(0, nk, P), jnp.int32),
                   jnp.asarray(rng.integers(1, 1500, P), jnp.int32),
                   jnp.asarray(rng.random(P) < 0.5),

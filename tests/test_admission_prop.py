@@ -43,7 +43,8 @@ def test_admission_op_parity_random(seed, P, nk, maxcap, p_want):
     size = jnp.asarray(rng.integers(0, 2000, P), jnp.int32)
     want = jnp.asarray(rng.random(P) < p_want)
     cap = jnp.asarray(rng.integers(0, maxcap + 1, nk), jnp.int32)
-    a_k, u_k = ops.admission_admit(key, size, want, cap, num_keys=nk)
+    a_k, u_k = ops.admission_admit(key, size, want, cap, num_keys=nk,
+                                   interpret=True)
     a_x, u_x = _group_admit(key, size, want, cap, nk)
     np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_x))
     np.testing.assert_array_equal(np.asarray(u_k), np.asarray(u_x))
