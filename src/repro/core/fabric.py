@@ -90,6 +90,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import tracing
 from .routing import CompiledRouting, first_direct_offsets
 from .telemetry import (TELE_KEYS, TelemetryConfig, TelemetryCounters,
                         counters_from_out)
@@ -367,10 +368,12 @@ def _admit(key, size, want, cap_left, num_keys, C=ADMIT_C, impl="xla",
         local_bytes = jax.ops.segment_sum(
             jnp.where(want, size, 0), jnp.where(want, key, num_keys),
             num_segments=num_keys + 1)[:num_keys]
-        offs = shard_group_offsets(local_bytes, axis, num_shards)
+        with jax.named_scope(tracing.EXCHANGE):
+            offs = shard_group_offsets(local_bytes, axis, num_shards)
         admitted, used = _group_admit_impl(
             key, size, want, cap_left - offs, num_keys, impl)
-        return admitted, jax.lax.psum(used, axis)
+        with jax.named_scope(tracing.EXCHANGE):
+            return admitted, jax.lax.psum(used, axis)
     if P <= C:
         return _group_admit_impl(key, size, want, cap_left, num_keys, impl)
     return jax.lax.cond(
@@ -469,33 +472,39 @@ def simulate(tables: FabricTables, wl: Workload, cfg: FabricConfig,
     _check_impls(cfg)
     T, N, U = tables.conn.shape
     dev = lambda a, dt=jnp.int32: jnp.asarray(a, dt)
-    j = dict(
-        conn=dev(tables.conn), tf_next=dev(tables.tf_next), tf_dep=dev(tables.tf_dep),
-        inj_next=dev(tables.inj_next), inj_dep=dev(tables.inj_dep),
-        first_direct=dev(tables.first_direct),
-        src=dev(wl.src), dst=dev(wl.dst), size=dev(wl.size),
-        t_inject=dev(wl.t_inject), flow=dev(wl.flow), seq=dev(wl.seq),
-        is_eleph=dev(wl.is_eleph, jnp.bool_),
-    )
-    if failures is not None:
-        failures.validate(num_slices, N)
-        j["link_cap"] = dev(failures.link_cap, jnp.float32)
-        j["node_ok"] = dev(failures.node_ok, jnp.bool_)
-    if control is not None:
-        if cfg.lookup_impl != "jnp":
-            raise ValueError(
-                "control-plane masks need lookup_impl='jnp': per-ToR local "
-                f"slices make lookups per-packet in time (got "
-                f"{cfg.lookup_impl!r})")
-        control.validate(num_slices, N)
-        j["phase_off"] = dev(control.phase_off)
-        j["skew_miss"] = dev(control.skew_miss, jnp.bool_)
+    with tracing.span("run.to_device"):
+        j = dict(
+            conn=dev(tables.conn), tf_next=dev(tables.tf_next), tf_dep=dev(tables.tf_dep),
+            inj_next=dev(tables.inj_next), inj_dep=dev(tables.inj_dep),
+            first_direct=dev(tables.first_direct),
+            src=dev(wl.src), dst=dev(wl.dst), size=dev(wl.size),
+            t_inject=dev(wl.t_inject), flow=dev(wl.flow), seq=dev(wl.seq),
+            is_eleph=dev(wl.is_eleph, jnp.bool_),
+        )
+        if failures is not None:
+            failures.validate(num_slices, N)
+            j["link_cap"] = dev(failures.link_cap, jnp.float32)
+            j["node_ok"] = dev(failures.node_ok, jnp.bool_)
+        if control is not None:
+            if cfg.lookup_impl != "jnp":
+                raise ValueError(
+                    "control-plane masks need lookup_impl='jnp': per-ToR local "
+                    f"slices make lookups per-packet in time (got "
+                    f"{cfg.lookup_impl!r})")
+            control.validate(num_slices, N)
+            j["phase_off"] = dev(control.phase_off)
+            j["skew_miss"] = dev(control.skew_miss, jnp.bool_)
     per_packet_mp = tables.multipath == "packet"
-    out = _simulate_jit(j, cfg, num_slices, per_packet_mp,
-                        int(max(wl.flow.max() + 1, 1)) if wl.num_packets else 1,
-                        telemetry)
-    out = {k: np.asarray(v) for k, v in out.items()}
-    tele = counters_from_out(out, telemetry)
+    with tracing.span("run.dispatch"):
+        out = _simulate_jit(
+            j, cfg, num_slices, per_packet_mp,
+            int(max(wl.flow.max() + 1, 1)) if wl.num_packets else 1,
+            telemetry)
+    with tracing.span("run.device_wait"):
+        out = jax.block_until_ready(out)
+    with tracing.span("run.result_copy"):
+        out = {k: np.asarray(v) for k, v in out.items()}
+        tele = counters_from_out(out, telemetry)
     return SimResult(**out, telemetry=tele)
 
 
@@ -575,13 +584,22 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
     # every update of a replicated aggregate is exchanged before its next
     # read so all shards keep bit-identical copies.
     def gsum(x):
-        return jax.lax.psum(x, axis) if axis is not None else x
+        if axis is None:
+            return x
+        with jax.named_scope(tracing.EXCHANGE):
+            return jax.lax.psum(x, axis)
 
     def gmin(x):
-        return jax.lax.pmin(x, axis) if axis is not None else x
+        if axis is None:
+            return x
+        with jax.named_scope(tracing.EXCHANGE):
+            return jax.lax.pmin(x, axis)
 
     def gmax(x):
-        return jax.lax.pmax(x, axis) if axis is not None else x
+        if axis is None:
+            return x
+        with jax.named_scope(tracing.EXCHANGE):
+            return jax.lax.pmax(x, axis)
 
     def upd_add(target, *updates):
         """Apply masked scatter-adds to a replicated aggregate; sharded,
@@ -594,7 +612,7 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
         d = jnp.zeros_like(target)
         for idx, vals, mask in updates:
             d = _scatter_add_masked(d, idx, vals, mask)
-        return target + jax.lax.psum(d, axis)
+        return target + gsum(d)
 
     # Control-plane masks (repro.core.controlplane): when present, each
     # ToR consults its tables at its *local* slice (t + phase_off) and a
@@ -643,7 +661,8 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
         if axis is None:
             return j[name][mt(t)]
         from ..distributed.collectives import gather_node_row
-        return gather_node_row(j[name][mt(t)], axis, N)
+        with jax.named_scope(tracing.EXCHANGE):
+            return gather_node_row(j[name][mt(t)], axis, N)
 
     caps_all = _build_caps_all(j["conn"], cfg, N)          # [T, NKEY]
 
@@ -729,6 +748,10 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
         return _hash32(base.astype(jnp.uint32) + salt)
 
     def step(state, t):
+        with jax.named_scope("fabric"):
+            return phases(state, t)
+
+    def phases(state, t):
         s = dict(state)
         if has_tele:
             # per-slice accumulators: zeroed here, filled by the phases
@@ -756,20 +779,25 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
                 v["h"] = h
                 v.update(extras)
                 return v, None
-            idx = _compact_idx(mask, C)
-            okc = idx < P
-            ic = jnp.clip(idx, 0, P - 1)
-            v = {k: s[k][ic] for k in fields}
-            v.update({k: a[ic] for k, a in CONSTS.items()})
-            v["h"] = h[ic]
-            v.update({k: a[ic] & okc for k, a in extras.items()})
-            v["_ok"] = okc
+            with jax.named_scope("compact"):
+                idx = _compact_idx(mask, C)
+                okc = idx < P
+                ic = jnp.clip(idx, 0, P - 1)
+                v = {k: s[k][ic] for k in fields}
+                v.update({k: a[ic] for k, a in CONSTS.items()})
+                v["h"] = h[ic]
+                v.update({k: a[ic] & okc for k, a in extras.items()})
+                v["_ok"] = okc
             return v, idx
 
         def write_view(s, v, fields, idx):
             s = dict(s)
-            for k in fields:
-                s[k] = v[k] if idx is None else s[k].at[idx].set(v[k], mode="drop")
+            if idx is None:
+                s.update((k, v[k]) for k in fields)
+                return s
+            with jax.named_scope("scatter_back"):
+                for k in fields:
+                    s[k] = s[k].at[idx].set(v[k], mode="drop")
             return s
 
         def enqueue_checks(s, v, arrived, off):
@@ -810,114 +838,119 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
                                 lambda op: (dict(op[0]), dict(op[1])), (s, v))
 
         # -- 0. calendar queues activating this slice leave the occupancy map
-        act = (s["loc"] >= 0) & (s["dep"] == t)
-        if uncond:
-            s["occ"] = upd_add(
-                s["occ"],
-                (jnp.clip(s["loc"], 0, N - 1) * T2 + t % T2, -j["size"], act))
-        else:
-            s["occ"] = jax.lax.cond(
-                jnp.any(act),
-                lambda occ: _scatter_add_masked(
-                    occ, jnp.clip(s["loc"], 0, N - 1) * T2 + t % T2,
-                    -j["size"], act),
-                lambda occ: occ, s["occ"])
+        with jax.named_scope("activate"):
+            act = (s["loc"] >= 0) & (s["dep"] == t)
+            if uncond:
+                s["occ"] = upd_add(
+                    s["occ"],
+                    (jnp.clip(s["loc"], 0, N - 1) * T2 + t % T2, -j["size"], act))
+            else:
+                s["occ"] = jax.lax.cond(
+                    jnp.any(act),
+                    lambda occ: _scatter_add_masked(
+                        occ, jnp.clip(s["loc"], 0, N - 1) * T2 + t % T2,
+                        -j["size"], act),
+                    lambda occ: occ, s["occ"])
 
         # -- 1+2. injection & re-lookup of deferred packets (fused lookup) ---
-        ready = (j["t_inject"] <= t) & (s["loc"] == NOT_INJECTED)
-        if has_fail:
-            # a down ToR's hosts cannot inject; the packets simply retry
-            # next slice (loc stays NOT_INJECTED)
-            ready &= no_t[j["src"]]
-        redo = s["relook"] & (s["loc"] >= 0) & (s["dep"] == t)
+        with jax.named_scope("inject"):
+            ready = (j["t_inject"] <= t) & (s["loc"] == NOT_INJECTED)
+            if has_fail:
+                # a down ToR's hosts cannot inject; the packets simply retry
+                # next slice (loc stays NOT_INJECTED)
+                ready &= no_t[j["src"]]
+            redo = s["relook"] & (s["loc"] >= 0) & (s["dep"] == t)
 
-        def inj_redo_logic(s, v):
-            if cfg.lookup_impl == "jnp":
-                # one gather serves both phases: injection reads the inj
-                # table at src, deferred packets read the transit table at loc
-                sel = jnp.where(v["ready"], 0, 1)
-                node = jnp.where(v["ready"], v["src"], jnp.clip(v["loc"], 0, N - 1))
-                # a skewed ToR looks its tables up at its *local* slice
-                tl = t + po_t[node] if has_ctrl else t
-                if has_vers:
-                    # each ToR reads the table version its install state
-                    # selects (old / new / safe) — mixed-version epochs
-                    vn = j["vsel"][t - j["vsel_t0"], node]
-                    row_n = stk_n[sel, vn, tl % Tr, node, v["dst"]]
-                    row_d = stk_d[sel, vn, tl % Tr, node, v["dst"]]
+            def inj_redo_logic(s, v):
+                with jax.named_scope("lookup"):
+                    if cfg.lookup_impl == "jnp":
+                        # one gather serves both phases: injection reads the inj
+                        # table at src, deferred packets read the transit table at loc
+                        sel = jnp.where(v["ready"], 0, 1)
+                        node = jnp.where(v["ready"], v["src"], jnp.clip(v["loc"], 0, N - 1))
+                        # a skewed ToR looks its tables up at its *local* slice
+                        tl = t + po_t[node] if has_ctrl else t
+                        if has_vers:
+                            # each ToR reads the table version its install state
+                            # selects (old / new / safe) — mixed-version epochs
+                            vn = j["vsel"][t - j["vsel_t0"], node]
+                            row_n = stk_n[sel, vn, tl % Tr, node, v["dst"]]
+                            row_d = stk_d[sel, vn, tl % Tr, node, v["dst"]]
+                        else:
+                            row_n = stk_n[sel, tl % Tr, node, v["dst"]]
+                            row_d = stk_d[sel, tl % Tr, node, v["dst"]]
+                        nxt_i, off_i = _select_slot(row_n, row_d, v["h"])
+                        nxt_r, off_r = nxt_i, off_i
+                    else:
+                        nxt_i, off_i = _lookup(j["inj_next"], j["inj_dep"], t,
+                                               v["src"], v["dst"], v["h"], cfg.lookup_impl)
+                        nxt_r, off_r = _lookup(j["tf_next"], j["tf_dep"], t,
+                                               jnp.clip(v["loc"], 0, N - 1), v["dst"],
+                                               v["h"], cfg.lookup_impl)
+                    if cfg.flow_pausing:
+                        # elephants wait for the direct circuit their *source ToR*
+                        # believes is coming (its local clock)
+                        tsrc = t + po_t[v["src"]] if has_ctrl else t
+                        fd = j["first_direct"][tsrc % T, v["src"], v["dst"]]
+                        use_direct = v["is_eleph"] & (fd >= 0)
+                        nxt_i = jnp.where(use_direct, v["dst"], nxt_i)
+                        off_i = jnp.where(use_direct, fd, off_i)
+                if cfg.pushback:
+                    # hosts hold traffic whose *target* slice bucket was pushed back
+                    blocked = s["block_until"][v["dst"], (t + off_i) % T] > t
                 else:
-                    row_n = stk_n[sel, tl % Tr, node, v["dst"]]
-                    row_d = stk_d[sel, tl % Tr, node, v["dst"]]
-                nxt_i, off_i = _select_slot(row_n, row_d, v["h"])
-                nxt_r, off_r = nxt_i, off_i
-            else:
-                nxt_i, off_i = _lookup(j["inj_next"], j["inj_dep"], t,
-                                       v["src"], v["dst"], v["h"], cfg.lookup_impl)
-                nxt_r, off_r = _lookup(j["tf_next"], j["tf_dep"], t,
-                                       jnp.clip(v["loc"], 0, N - 1), v["dst"],
-                                       v["h"], cfg.lookup_impl)
-            if cfg.flow_pausing:
-                # elephants wait for the direct circuit their *source ToR*
-                # believes is coming (its local clock)
-                tsrc = t + po_t[v["src"]] if has_ctrl else t
-                fd = j["first_direct"][tsrc % T, v["src"], v["dst"]]
-                use_direct = v["is_eleph"] & (fd >= 0)
-                nxt_i = jnp.where(use_direct, v["dst"], nxt_i)
-                off_i = jnp.where(use_direct, fd, off_i)
-            if cfg.pushback:
-                # hosts hold traffic whose *target* slice bucket was pushed back
-                blocked = s["block_until"][v["dst"], (t + off_i) % T] > t
-            else:
-                blocked = jnp.zeros(v["ready"].shape, bool)
-            inject = v["ready"] & ~blocked
-            if has_tele:
-                s["_tin"] = upd_add(
-                    s["_tin"],
-                    (jnp.clip(v["src"], 0, N - 1), v["size"], inject))
-            v["loc"] = jnp.where(inject, v["src"], v["loc"])
-            v["nxt"] = jnp.where(inject, nxt_i, v["nxt"])
-            v["dep"] = jnp.where(inject, t + off_i, v["dep"])
-            s["occ"] = upd_add(s["occ"], (vbucket(v, t + off_i), v["size"],
-                                          inject & (off_i > 0)))
-            s, v = enqueue_checks(s, v, inject, jnp.where(inject, off_i, 0))
-            n_blocked = jnp.sum(v["ready"] & blocked)
-            # deferred packets re-enter the pipeline with a fresh action
-            v["nxt"] = jnp.where(v["redo"], nxt_r, v["nxt"])
-            v["dep"] = jnp.where(v["redo"], t + off_r, v["dep"])
-            v["relook"] = v["relook"] & ~v["redo"]
-            s["occ"] = upd_add(s["occ"], (vbucket(v, t + off_r), v["size"],
-                                          v["redo"] & (off_r > 0)))
-            return s, v, n_blocked
+                    blocked = jnp.zeros(v["ready"].shape, bool)
+                inject = v["ready"] & ~blocked
+                if has_tele:
+                    s["_tin"] = upd_add(
+                        s["_tin"],
+                        (jnp.clip(v["src"], 0, N - 1), v["size"], inject))
+                v["loc"] = jnp.where(inject, v["src"], v["loc"])
+                v["nxt"] = jnp.where(inject, nxt_i, v["nxt"])
+                v["dep"] = jnp.where(inject, t + off_i, v["dep"])
+                with jax.named_scope("enqueue"):
+                    s["occ"] = upd_add(s["occ"], (vbucket(v, t + off_i), v["size"],
+                                                  inject & (off_i > 0)))
+                    s, v = enqueue_checks(s, v, inject, jnp.where(inject, off_i, 0))
+                n_blocked = jnp.sum(v["ready"] & blocked)
+                # deferred packets re-enter the pipeline with a fresh action
+                v["nxt"] = jnp.where(v["redo"], nxt_r, v["nxt"])
+                v["dep"] = jnp.where(v["redo"], t + off_r, v["dep"])
+                v["relook"] = v["relook"] & ~v["redo"]
+                with jax.named_scope("enqueue"):
+                    s["occ"] = upd_add(s["occ"], (vbucket(v, t + off_r), v["size"],
+                                                  v["redo"] & (off_r > 0)))
+                return s, v, n_blocked
 
-        inj_mask = ready | redo
-        inj_cnt = jnp.sum(inj_mask)
+            inj_mask = ready | redo
+            inj_cnt = jnp.sum(inj_mask)
 
-        def inj_full(s):
-            v, idx = make_view(s, INJ_FIELDS, None, dict(ready=ready, redo=redo), None)
-            s, v, n_blocked = inj_redo_logic(dict(s), v)
-            return write_view(s, v, INJ_FIELDS, idx), n_blocked
-
-        def inj_compact(C):
-            def fn(s, C=C):
-                v, idx = make_view(s, INJ_FIELDS, inj_mask,
-                                   dict(ready=ready, redo=redo), C)
+            def inj_full(s):
+                v, idx = make_view(s, INJ_FIELDS, None, dict(ready=ready, redo=redo), None)
                 s, v, n_blocked = inj_redo_logic(dict(s), v)
                 return write_view(s, v, INJ_FIELDS, idx), n_blocked
-            return fn
 
-        if uncond:
-            # unconditional: the injection exchange collectives must run on
-            # every shard even when this shard has nothing to inject
-            s, n_blocked = inj_full(s)
-            n_blocked = gsum(n_blocked)
-        else:
-            inj_fn = inj_full
-            for c in TIERS[::-1]:
-                inj_fn = (lambda s, cc=c, inner=inj_fn:
-                          jax.lax.cond(inj_cnt <= cc, inj_compact(cc), inner, s))
-            s, n_blocked = jax.lax.cond(
-                inj_cnt > 0, inj_fn,
-                lambda s: (dict(s), jnp.zeros((), jnp.int32)), s)
+            def inj_compact(C):
+                def fn(s, C=C):
+                    v, idx = make_view(s, INJ_FIELDS, inj_mask,
+                                       dict(ready=ready, redo=redo), C)
+                    s, v, n_blocked = inj_redo_logic(dict(s), v)
+                    return write_view(s, v, INJ_FIELDS, idx), n_blocked
+                return fn
+
+            if uncond:
+                # unconditional: the injection exchange collectives must run on
+                # every shard even when this shard has nothing to inject
+                s, n_blocked = inj_full(s)
+                n_blocked = gsum(n_blocked)
+            else:
+                inj_fn = inj_full
+                for c in TIERS[::-1]:
+                    inj_fn = (lambda s, cc=c, inner=inj_fn:
+                              jax.lax.cond(inj_cnt <= cc, inj_compact(cc), inner, s))
+                s, n_blocked = jax.lax.cond(
+                    inj_cnt > 0, inj_fn,
+                    lambda s: (dict(s), jnp.zeros((), jnp.int32)), s)
 
         def on_switch_bytes(occ):
             """Per-node switch-resident bytes: occupancy columns within the
@@ -949,77 +982,78 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
                 # misses its slice and re-enqueues via the §5.2 machinery.
                 want &= ~(sm_t[jnp.clip(v["loc"], 0, N - 1)] &
                           (v["nxt"] < N))
-            if cfg.pushback:
-                # push-back rejects at the *sender*: no transmission into a
-                # full downstream switch (paper §5.2); rejected packets miss
-                # the slice and defer instead of being dropped on arrival.
-                # FIFO admission against the receiver's remaining buffer room.
-                need_buf = want & (v["nxt"] < N) & (v["nxt"] != v["dst"])
-                room = jnp.maximum(cfg.switch_buffer - buf_now, 0)
-                adm_rx, _ = _admit(jnp.clip(v["nxt"], 0, N - 1), v["size"],
-                                   need_buf, room, N, impl=cfg.admit_impl,
-                                   axis=axis, num_shards=num_shards)
-                # rx rejections are monotone within the slice: the rx cut is
-                # a FIFO prefix per receiver, a receiver's room only shrinks
-                # (buf_now only receives arrivals), and a candidate's rx
-                # prefix can drop only by bytes of earlier same-receiver
-                # packets that transmitted — each of which arrived at that
-                # receiver, shrinking room by at least as much. The first
-                # rx-rejected index per receiver therefore poisons its whole
-                # suffix for the rest of the slice.
-                rej_rx = need_buf & ~adm_rx
-                rx_backlog_min = rx_backlog_min.at[
-                    jnp.where(rej_rx, jnp.clip(v["nxt"], 0, N - 1), 0)].min(
-                    jnp.where(rej_rx, v["gidx"], PG))
-                want &= adm_rx | ~need_buf
-            key = jnp.clip(v["loc"], 0, N - 1) * (N + 1) + jnp.clip(v["nxt"], 0, N)
-            admitted, consumed = _admit(key, v["size"], want, caps - used,
-                                        NKEY, impl=cfg.admit_impl,
-                                        axis=axis, num_shards=num_shards)
-            used = used + consumed
-            if "adm_shard" in v:
-                # ownership trace: only the shard whose block holds the
-                # packet ever admits it (its peers hold no copy), which the
-                # toolkit sharding checker asserts
-                v["adm_shard"] = jnp.where(admitted, shard, v["adm_shard"])
-            # Rejected packets form the slice's backlog: admission is a
-            # cumulative-prefix cut per group and capacities only shrink, so a
-            # packet positioned after a rejected one in its group can never be
-            # admitted later this slice. Remember the minimum rejected index
-            # per group; later hops drop those provably-rejected candidates.
-            if not cfg.pushback:
-                # only *wanted* rejections poison the suffix: packets cut
-                # from want by failure/skew masks never consumed capacity
-                # and must not filter their healthy group-mates
-                rejected = want & ~admitted
-                backlog_min = backlog_min.at[jnp.where(rejected, key, 0)].min(
-                    jnp.where(rejected, v["gidx"], PG))
-            else:
-                # Under push-back the only bytes that can ever *leave* a
-                # candidate's capacity prefix belong to an earlier
-                # same-group member that was rx-admitted but
-                # capacity-rejected this slice: it stays a candidate and
-                # may flip to rx-rejected at a later hop (capacity-admitted
-                # members transmitted — their bytes became consumed
-                # capacity and never come back; rx-rejected members were
-                # never in the prefix). Track the first such "rescuable"
-                # index per group; an rx-exempt candidate (electrical, or
-                # delivering directly to its destination) rejected with no
-                # rescuable predecessor is then provably rejected for the
-                # rest of the slice. rx-subject rejections are never
-                # marked: their bytes participate in other candidates' rx
-                # prefixes, and cutting them would perturb the rx cut.
-                resc = need_buf & adm_rx & ~admitted
-                resc_min = resc_min.at[jnp.where(resc, key, 0)].min(
-                    jnp.where(resc, v["gidx"], PG))
-                # the markable test reads resc_min across *all* packets of
-                # the group, so the per-shard partial mins are exchanged
-                # before the read
-                resc_min = gmin(resc_min)
-                markable = want & ~admitted & ~need_buf & \
-                    (v["gidx"] < resc_min[key])
-                backlog_min = backlog_min.at[jnp.where(markable, key, 0)].min(
-                    jnp.where(markable, v["gidx"], PG))
+            with jax.named_scope("admit"):
+                if cfg.pushback:
+                    # push-back rejects at the *sender*: no transmission into a
+                    # full downstream switch (paper §5.2); rejected packets miss
+                    # the slice and defer instead of being dropped on arrival.
+                    # FIFO admission against the receiver's remaining buffer room.
+                    need_buf = want & (v["nxt"] < N) & (v["nxt"] != v["dst"])
+                    room = jnp.maximum(cfg.switch_buffer - buf_now, 0)
+                    adm_rx, _ = _admit(jnp.clip(v["nxt"], 0, N - 1), v["size"],
+                                       need_buf, room, N, impl=cfg.admit_impl,
+                                       axis=axis, num_shards=num_shards)
+                    # rx rejections are monotone within the slice: the rx cut is
+                    # a FIFO prefix per receiver, a receiver's room only shrinks
+                    # (buf_now only receives arrivals), and a candidate's rx
+                    # prefix can drop only by bytes of earlier same-receiver
+                    # packets that transmitted — each of which arrived at that
+                    # receiver, shrinking room by at least as much. The first
+                    # rx-rejected index per receiver therefore poisons its whole
+                    # suffix for the rest of the slice.
+                    rej_rx = need_buf & ~adm_rx
+                    rx_backlog_min = rx_backlog_min.at[
+                        jnp.where(rej_rx, jnp.clip(v["nxt"], 0, N - 1), 0)].min(
+                        jnp.where(rej_rx, v["gidx"], PG))
+                    want &= adm_rx | ~need_buf
+                key = jnp.clip(v["loc"], 0, N - 1) * (N + 1) + jnp.clip(v["nxt"], 0, N)
+                admitted, consumed = _admit(key, v["size"], want, caps - used,
+                                            NKEY, impl=cfg.admit_impl,
+                                            axis=axis, num_shards=num_shards)
+                used = used + consumed
+                if "adm_shard" in v:
+                    # ownership trace: only the shard whose block holds the
+                    # packet ever admits it (its peers hold no copy), which the
+                    # toolkit sharding checker asserts
+                    v["adm_shard"] = jnp.where(admitted, shard, v["adm_shard"])
+                # Rejected packets form the slice's backlog: admission is a
+                # cumulative-prefix cut per group and capacities only shrink, so a
+                # packet positioned after a rejected one in its group can never be
+                # admitted later this slice. Remember the minimum rejected index
+                # per group; later hops drop those provably-rejected candidates.
+                if not cfg.pushback:
+                    # only *wanted* rejections poison the suffix: packets cut
+                    # from want by failure/skew masks never consumed capacity
+                    # and must not filter their healthy group-mates
+                    rejected = want & ~admitted
+                    backlog_min = backlog_min.at[jnp.where(rejected, key, 0)].min(
+                        jnp.where(rejected, v["gidx"], PG))
+                else:
+                    # Under push-back the only bytes that can ever *leave* a
+                    # candidate's capacity prefix belong to an earlier
+                    # same-group member that was rx-admitted but
+                    # capacity-rejected this slice: it stays a candidate and
+                    # may flip to rx-rejected at a later hop (capacity-admitted
+                    # members transmitted — their bytes became consumed
+                    # capacity and never come back; rx-rejected members were
+                    # never in the prefix). Track the first such "rescuable"
+                    # index per group; an rx-exempt candidate (electrical, or
+                    # delivering directly to its destination) rejected with no
+                    # rescuable predecessor is then provably rejected for the
+                    # rest of the slice. rx-subject rejections are never
+                    # marked: their bytes participate in other candidates' rx
+                    # prefixes, and cutting them would perturb the rx cut.
+                    resc = need_buf & adm_rx & ~admitted
+                    resc_min = resc_min.at[jnp.where(resc, key, 0)].min(
+                        jnp.where(resc, v["gidx"], PG))
+                    # the markable test reads resc_min across *all* packets of
+                    # the group, so the per-shard partial mins are exchanged
+                    # before the read
+                    resc_min = gmin(resc_min)
+                    markable = want & ~admitted & ~need_buf & \
+                        (v["gidx"] < resc_min[key])
+                    backlog_min = backlog_min.at[jnp.where(markable, key, 0)].min(
+                        jnp.where(markable, v["gidx"], PG))
             is_elec = admitted & (v["nxt"] == N)
             moved = admitted & ~is_elec
             newloc = jnp.where(moved, v["nxt"], v["loc"])
@@ -1030,78 +1064,81 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
 
             # reorder accounting (deliveries are capacity-bounded per hop, so
             # the compact path is the common case even for a full-width view)
-            Pv = v["loc"].shape[0]
+            with jax.named_scope("reorder"):
+                Pv = v["loc"].shape[0]
 
-            def _re_small(ms):
-                max_seq, reorder = ms
-                i2 = _compact_idx(at_dst, SMALL_C)
-                ok2 = i2 < Pv
-                ci = jnp.clip(i2, 0, Pv - 1)
-                fl = jnp.where(ok2, v["flow"][ci], 0)
-                sq = jnp.where(ok2, v["seq"][ci], -1)
-                prev = max_seq[fl]
-                reorder = reorder + jnp.sum(ok2 & (sq < prev))
-                return max_seq.at[fl].max(jnp.where(ok2, sq, -1)), reorder
+                def _re_small(ms):
+                    max_seq, reorder = ms
+                    i2 = _compact_idx(at_dst, SMALL_C)
+                    ok2 = i2 < Pv
+                    ci = jnp.clip(i2, 0, Pv - 1)
+                    fl = jnp.where(ok2, v["flow"][ci], 0)
+                    sq = jnp.where(ok2, v["seq"][ci], -1)
+                    prev = max_seq[fl]
+                    reorder = reorder + jnp.sum(ok2 & (sq < prev))
+                    return max_seq.at[fl].max(jnp.where(ok2, sq, -1)), reorder
 
-            def _re_full(ms):
-                max_seq, reorder = ms
-                prev = max_seq[v["flow"]]
-                reorder = reorder + jnp.sum(at_dst & (v["seq"] < prev))
-                return max_seq.at[jnp.where(at_dst, v["flow"], 0)].max(
-                    jnp.where(at_dst, v["seq"], -1)), reorder
+                def _re_full(ms):
+                    max_seq, reorder = ms
+                    prev = max_seq[v["flow"]]
+                    reorder = reorder + jnp.sum(at_dst & (v["seq"] < prev))
+                    return max_seq.at[jnp.where(at_dst, v["flow"], 0)].max(
+                        jnp.where(at_dst, v["seq"], -1)), reorder
 
-            if Pv <= SMALL_C:
-                s["max_seq"], s["reorder"] = _re_full((s["max_seq"], s["reorder"]))
-            else:
-                s["max_seq"], s["reorder"] = jax.lax.cond(
-                    jnp.sum(at_dst) <= SMALL_C, _re_small, _re_full,
-                    (s["max_seq"], s["reorder"]))
-            # max_seq is replicated high-water state: exchange before the
-            # next hop's reads. reorder stays a per-shard partial count
-            # (each shard saw only its own deliveries against the *global*
-            # max_seq) and is summed once at the end of the run.
-            s["max_seq"] = gmax(s["max_seq"])
+                if Pv <= SMALL_C:
+                    s["max_seq"], s["reorder"] = _re_full((s["max_seq"], s["reorder"]))
+                else:
+                    s["max_seq"], s["reorder"] = jax.lax.cond(
+                        jnp.sum(at_dst) <= SMALL_C, _re_small, _re_full,
+                        (s["max_seq"], s["reorder"]))
+                # max_seq is replicated high-water state: exchange before the
+                # next hop's reads. reorder stays a per-shard partial count
+                # (each shard saw only its own deliveries against the *global*
+                # max_seq) and is summed once at the end of the run.
+                s["max_seq"] = gmax(s["max_seq"])
 
             v["loc"] = jnp.where(at_dst, DELIVERED, newloc)
             v["nhops"] = v["nhops"] + admitted.astype(jnp.int32)
             # transit lookup at the new node (its local slice, its version)
             in_transit = moved & ~at_dst
-            node_t = jnp.clip(v["loc"], 0, N - 1)
-            tl = t + po_t[node_t] if has_ctrl else t
-            if has_vers:
-                vn = j["vsel"][t - j["vsel_t0"], node_t]
-                rn = j["tf_next_v"][vn, tl % Tr, node_t, v["dst"]]
-                rd = j["tf_dep_v"][vn, tl % Tr, node_t, v["dst"]]
-                nxt_t, off_t = _select_slot(rn, rd, v["h"])
-            else:
-                nxt_t, off_t = _lookup(j["tf_next"], j["tf_dep"], tl,
-                                       node_t, v["dst"], v["h"],
-                                       cfg.lookup_impl)
-            v["nxt"] = jnp.where(in_transit, nxt_t, v["nxt"])
-            v["dep"] = jnp.where(in_transit, t + off_t, v["dep"])
-            # buffer-overflow drops on arrival at a new switch; a rejection
-            # also pushes the sender back (paper §5.2)
-            buf_now = upd_add(buf_now, (jnp.clip(v["loc"], 0, N - 1),
-                                        v["size"], in_transit))
-            if has_tele:
-                s["_thwm"] = jnp.maximum(s["_thwm"], buf_now)
-            overflow = in_transit & \
-                (buf_now[jnp.clip(v["loc"], 0, N - 1)] > cfg.switch_buffer)
-            if cfg.pushback:
-                upd = jnp.where(overflow, t + T, 0)
-                s["block_until"] = s["block_until"].at[
-                    jnp.where(overflow, v["dst"], 0), v["dep"] % T].max(upd)
-            if has_tele:
-                # count dropped bytes at the switch the packet overflowed,
-                # before loc is overwritten with the DROPPED sentinel
-                s["_tdrop"] = upd_add(
-                    s["_tdrop"],
-                    (jnp.clip(v["loc"], 0, N - 1), v["size"], overflow))
-            v["loc"] = jnp.where(overflow, DROPPED, v["loc"])
-            arrived = in_transit & ~overflow
-            s["occ"] = upd_add(s["occ"], (vbucket(v, t + off_t), v["size"],
-                                          arrived & (off_t > 0)))
-            s, v = enqueue_checks(s, v, arrived, jnp.where(in_transit, off_t, 0))
+            with jax.named_scope("lookup"):
+                node_t = jnp.clip(v["loc"], 0, N - 1)
+                tl = t + po_t[node_t] if has_ctrl else t
+                if has_vers:
+                    vn = j["vsel"][t - j["vsel_t0"], node_t]
+                    rn = j["tf_next_v"][vn, tl % Tr, node_t, v["dst"]]
+                    rd = j["tf_dep_v"][vn, tl % Tr, node_t, v["dst"]]
+                    nxt_t, off_t = _select_slot(rn, rd, v["h"])
+                else:
+                    nxt_t, off_t = _lookup(j["tf_next"], j["tf_dep"], tl,
+                                           node_t, v["dst"], v["h"],
+                                           cfg.lookup_impl)
+                v["nxt"] = jnp.where(in_transit, nxt_t, v["nxt"])
+                v["dep"] = jnp.where(in_transit, t + off_t, v["dep"])
+            with jax.named_scope("enqueue"):
+                # buffer-overflow drops on arrival at a new switch; a rejection
+                # also pushes the sender back (paper §5.2)
+                buf_now = upd_add(buf_now, (jnp.clip(v["loc"], 0, N - 1),
+                                            v["size"], in_transit))
+                if has_tele:
+                    s["_thwm"] = jnp.maximum(s["_thwm"], buf_now)
+                overflow = in_transit & \
+                    (buf_now[jnp.clip(v["loc"], 0, N - 1)] > cfg.switch_buffer)
+                if cfg.pushback:
+                    upd = jnp.where(overflow, t + T, 0)
+                    s["block_until"] = s["block_until"].at[
+                        jnp.where(overflow, v["dst"], 0), v["dep"] % T].max(upd)
+                if has_tele:
+                    # count dropped bytes at the switch the packet overflowed,
+                    # before loc is overwritten with the DROPPED sentinel
+                    s["_tdrop"] = upd_add(
+                        s["_tdrop"],
+                        (jnp.clip(v["loc"], 0, N - 1), v["size"], overflow))
+                v["loc"] = jnp.where(overflow, DROPPED, v["loc"])
+                arrived = in_transit & ~overflow
+                s["occ"] = upd_add(s["occ"], (vbucket(v, t + off_t), v["size"],
+                                              arrived & (off_t > 0)))
+                s, v = enqueue_checks(s, v, arrived, jnp.where(in_transit, off_t, 0))
             # the backlog cuts are read by every shard at the next hop's
             # want0 filter: exchange the per-shard partial minima
             backlog_min = gmin(backlog_min)
@@ -1112,134 +1149,138 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
         rx_backlog_min = jnp.full((N,), PG, jnp.int32)
         resc_min = jnp.full((NKEY,), PG, jnp.int32)
         for _hop in range(cfg.hops_per_slice):
-            want0 = (s["loc"] >= 0) & (s["dep"] == t) & (s["nxt"] >= 0) & \
-                    (s["nhops"] < cfg.max_hops)
-            key_all = jnp.clip(s["loc"], 0, N - 1) * (N + 1) + \
-                jnp.clip(s["nxt"], 0, N)
-            if not cfg.pushback:
-                want0 &= pid < backlog_min[key_all]
-            else:
-                # push-back-aware backlog filter: drop candidates at-or-after
-                # a receiver's first rx-rejected index (rx rejection is
-                # monotone — see hop_logic), and rx-exempt candidates
-                # strictly *after* their group's first marked capacity
-                # rejection (the marked packet itself stays in the sort as
-                # the byte anchor of every successor's over-capacity
-                # prefix). rx-subject capacity rejections stay unfiltered:
-                # their prefixes can lose bytes to later rx flips, and
-                # their bytes feed other candidates' rx prefixes.
-                rx_subject = (s["nxt"] >= 0) & (s["nxt"] < N) & \
-                    (s["nxt"] != j["dst"])
-                want0 &= ~(rx_subject &
-                           (pid >= rx_backlog_min[jnp.clip(s["nxt"], 0, N - 1)]))
-                want0 &= ~(~rx_subject & (pid > backlog_min[key_all]))
-            cnt0 = jnp.sum(want0)
+            with jax.named_scope("hop"):
+                with jax.named_scope("backlog_filter"):
+                    want0 = (s["loc"] >= 0) & (s["dep"] == t) & (s["nxt"] >= 0) & \
+                            (s["nhops"] < cfg.max_hops)
+                    key_all = jnp.clip(s["loc"], 0, N - 1) * (N + 1) + \
+                        jnp.clip(s["nxt"], 0, N)
+                    if not cfg.pushback:
+                        want0 &= pid < backlog_min[key_all]
+                    else:
+                        # push-back-aware backlog filter: drop candidates at-or-after
+                        # a receiver's first rx-rejected index (rx rejection is
+                        # monotone — see hop_logic), and rx-exempt candidates
+                        # strictly *after* their group's first marked capacity
+                        # rejection (the marked packet itself stays in the sort as
+                        # the byte anchor of every successor's over-capacity
+                        # prefix). rx-subject capacity rejections stay unfiltered:
+                        # their prefixes can lose bytes to later rx flips, and
+                        # their bytes feed other candidates' rx prefixes.
+                        rx_subject = (s["nxt"] >= 0) & (s["nxt"] < N) & \
+                            (s["nxt"] != j["dst"])
+                        want0 &= ~(rx_subject &
+                                   (pid >= rx_backlog_min[jnp.clip(s["nxt"], 0, N - 1)]))
+                        want0 &= ~(~rx_subject & (pid > backlog_min[key_all]))
+                cnt0 = jnp.sum(want0)
 
-            def hop_full(carry, want0=want0):
-                s, used, buf_now, backlog_min, rx_backlog_min, resc_min = carry
-                v, idx = make_view(s, HOP_FIELDS, None,
-                                   dict(active=want0), None)
-                v["gidx"] = pid
-                (s, v, used, buf_now, backlog_min, rx_backlog_min,
-                 resc_min) = hop_logic(dict(s), v, used, buf_now, backlog_min,
-                                       rx_backlog_min, resc_min)
-                return (write_view(s, v, HOP_FIELDS, idx), used, buf_now,
-                        backlog_min, rx_backlog_min, resc_min)
-
-            def hop_compact(C, want0=want0):
-                def fn(carry, C=C, want0=want0):
-                    (s, used, buf_now, backlog_min, rx_backlog_min,
-                     resc_min) = carry
-                    v, idx = make_view(s, HOP_FIELDS, want0, {}, C)
-                    v["active"] = v.pop("_ok")
-                    v["gidx"] = jnp.minimum(idx, P).astype(jnp.int32)
+                def hop_full(carry, want0=want0):
+                    s, used, buf_now, backlog_min, rx_backlog_min, resc_min = carry
+                    v, idx = make_view(s, HOP_FIELDS, None,
+                                       dict(active=want0), None)
+                    v["gidx"] = pid
                     (s, v, used, buf_now, backlog_min, rx_backlog_min,
-                     resc_min) = hop_logic(dict(s), v, used, buf_now,
-                                           backlog_min, rx_backlog_min,
-                                           resc_min)
+                     resc_min) = hop_logic(dict(s), v, used, buf_now, backlog_min,
+                                           rx_backlog_min, resc_min)
                     return (write_view(s, v, HOP_FIELDS, idx), used, buf_now,
                             backlog_min, rx_backlog_min, resc_min)
-                return fn
 
-            if uncond:
-                # every shard runs every hop: the admission exchange and
-                # aggregate reconciliation are collective
-                s, used, buf_now, backlog_min, rx_backlog_min, resc_min = \
-                    hop_full((s, used, buf_now, backlog_min, rx_backlog_min,
-                              resc_min))
-            else:
-                hop_fn = hop_full
-                for c in TIERS[::-1]:
-                    hop_fn = (lambda carry, cc=c, inner=hop_fn:
-                              jax.lax.cond(cnt0 <= cc, hop_compact(cc), inner,
-                                           carry))
-                s, used, buf_now, backlog_min, rx_backlog_min, resc_min = \
-                    jax.lax.cond(
-                        cnt0 == 0, lambda c: (dict(c[0]),) + c[1:], hop_fn,
+                def hop_compact(C, want0=want0):
+                    def fn(carry, C=C, want0=want0):
                         (s, used, buf_now, backlog_min, rx_backlog_min,
-                         resc_min))
+                         resc_min) = carry
+                        v, idx = make_view(s, HOP_FIELDS, want0, {}, C)
+                        v["active"] = v.pop("_ok")
+                        v["gidx"] = jnp.minimum(idx, P).astype(jnp.int32)
+                        (s, v, used, buf_now, backlog_min, rx_backlog_min,
+                         resc_min) = hop_logic(dict(s), v, used, buf_now,
+                                               backlog_min, rx_backlog_min,
+                                               resc_min)
+                        return (write_view(s, v, HOP_FIELDS, idx), used, buf_now,
+                                backlog_min, rx_backlog_min, resc_min)
+                    return fn
+
+                if uncond:
+                    # every shard runs every hop: the admission exchange and
+                    # aggregate reconciliation are collective
+                    s, used, buf_now, backlog_min, rx_backlog_min, resc_min = \
+                        hop_full((s, used, buf_now, backlog_min, rx_backlog_min,
+                                  resc_min))
+                else:
+                    hop_fn = hop_full
+                    for c in TIERS[::-1]:
+                        hop_fn = (lambda carry, cc=c, inner=hop_fn:
+                                  jax.lax.cond(cnt0 <= cc, hop_compact(cc), inner,
+                                               carry))
+                    s, used, buf_now, backlog_min, rx_backlog_min, resc_min = \
+                        jax.lax.cond(
+                            cnt0 == 0, lambda c: (dict(c[0]),) + c[1:], hop_fn,
+                            (s, used, buf_now, backlog_min, rx_backlog_min,
+                             resc_min))
 
         # -- 4. handle packets that missed their slice ----------------------
-        missed = (s["loc"] >= 0) & (s["dep"] == t)
-        miss_cnt = jnp.sum(missed)
+        with jax.named_scope("missed"):
+            missed = (s["loc"] >= 0) & (s["dep"] == t)
+            miss_cnt = jnp.sum(missed)
 
-        def missed_body(s):
-            s = dict(s)
-            bump = t + 1 if cfg.cc_detect else t + T  # paused a cycle (§5.2)
-            if cfg.cc_detect:
-                s["relook"] = s["relook"] | missed
-            s["occ"] = upd_add(
-                s["occ"], (jnp.clip(s["loc"], 0, N - 1) * T2 + bump % T2,
-                           j["size"], missed))
-            if has_tele:
-                s["_tdef"] = upd_add(
-                    s["_tdef"],
-                    (jnp.clip(s["loc"], 0, N - 1), j["size"], missed))
-            s["dep"] = jnp.where(missed, bump, s["dep"])
-            if cfg.pushback:
-                upd = jnp.where(missed, t + T, 0)
-                s["block_until"] = s["block_until"].at[j["dst"], t % T].max(upd)
-            return s
+            def missed_body(s):
+                s = dict(s)
+                bump = t + 1 if cfg.cc_detect else t + T  # paused a cycle (§5.2)
+                if cfg.cc_detect:
+                    s["relook"] = s["relook"] | missed
+                s["occ"] = upd_add(
+                    s["occ"], (jnp.clip(s["loc"], 0, N - 1) * T2 + bump % T2,
+                               j["size"], missed))
+                if has_tele:
+                    s["_tdef"] = upd_add(
+                        s["_tdef"],
+                        (jnp.clip(s["loc"], 0, N - 1), j["size"], missed))
+                s["dep"] = jnp.where(missed, bump, s["dep"])
+                if cfg.pushback:
+                    upd = jnp.where(missed, t + T, 0)
+                    s["block_until"] = s["block_until"].at[j["dst"], t % T].max(upd)
+                return s
 
-        if uncond:
-            s = missed_body(s)       # occ delta is psum-exchanged inside
-            miss_cnt = gsum(miss_cnt)
-        else:
-            s = jax.lax.cond(miss_cnt > 0, missed_body, lambda s: dict(s), s)
-        if axis is not None and cfg.pushback:
-            # block_until collected per-shard partial maxima all step
-            # (defer, overflow, missed sites); it is only read at the next
-            # slice's injection, so one exchange here keeps it replicated
-            s["block_until"] = gmax(s["block_until"])
+            if uncond:
+                s = missed_body(s)       # occ delta is psum-exchanged inside
+                miss_cnt = gsum(miss_cnt)
+            else:
+                s = jax.lax.cond(miss_cnt > 0, missed_body, lambda s: dict(s), s)
+            if axis is not None and cfg.pushback:
+                # block_until collected per-shard partial maxima all step
+                # (defer, overflow, missed sites); it is only read at the next
+                # slice's injection, so one exchange here keeps it replicated
+                s["block_until"] = gmax(s["block_until"])
 
         # -- 5. per-slice stats (column sums of the occupancy map) ----------
-        on_sw = on_switch_bytes(s["occ"])
-        if cfg.offload:
-            off_sw = s["occ"].reshape(N, T2).sum(axis=1) - on_sw
-        else:
-            off_sw = jnp.zeros_like(on_sw)
-        stats = dict(
-            delivered_bytes=gsum(
-                jnp.sum(jnp.where(s["t_del"] == t, j["size"], 0))),
-            dropped=gsum(jnp.sum(s["loc"] == DROPPED)),
-            buf_bytes=on_sw, offl_bytes=off_sw,
-            blocked_inj=n_blocked, slice_miss=miss_cnt,
-        )
-        if has_tele:
-            # circuit utilization: optical bytes moved vs granted, per
-            # source switch (the electrical egress column N is excluded).
-            # tele_delivered / tele_lat_hist are NOT accumulated here:
-            # delivery is terminal (t_del is written once), so both are
-            # reconstructed from the terminal packet state with one P-wide
-            # scatter per run (_tele_delivery_rows) instead of a
-            # full-population pass every slice.
-            stats.update(
-                tele_injected=s["_tin"],
-                tele_deferred=s["_tdef"], tele_dropped=s["_tdrop"],
-                tele_qhwm=jnp.maximum(s["_thwm"], on_sw),
-                tele_util_used=used.reshape(N, N + 1)[:, :N].sum(axis=1),
-                tele_util_cap=caps.reshape(N, N + 1)[:, :N].sum(axis=1),
+        with jax.named_scope("stats"):
+            on_sw = on_switch_bytes(s["occ"])
+            if cfg.offload:
+                off_sw = s["occ"].reshape(N, T2).sum(axis=1) - on_sw
+            else:
+                off_sw = jnp.zeros_like(on_sw)
+            stats = dict(
+                delivered_bytes=gsum(
+                    jnp.sum(jnp.where(s["t_del"] == t, j["size"], 0))),
+                dropped=gsum(jnp.sum(s["loc"] == DROPPED)),
+                buf_bytes=on_sw, offl_bytes=off_sw,
+                blocked_inj=n_blocked, slice_miss=miss_cnt,
             )
+            if has_tele:
+                # circuit utilization: optical bytes moved vs granted, per
+                # source switch (the electrical egress column N is excluded).
+                # tele_delivered / tele_lat_hist are NOT accumulated here:
+                # delivery is terminal (t_del is written once), so both are
+                # reconstructed from the terminal packet state with one P-wide
+                # scatter per run (_tele_delivery_rows) instead of a
+                # full-population pass every slice.
+                stats.update(
+                    tele_injected=s["_tin"],
+                    tele_deferred=s["_tdef"], tele_dropped=s["_tdrop"],
+                    tele_qhwm=jnp.maximum(s["_thwm"], on_sw),
+                    tele_util_used=used.reshape(N, N + 1)[:, :N].sum(axis=1),
+                    tele_util_cap=caps.reshape(N, N + 1)[:, :N].sum(axis=1),
+                )
         return s, stats
 
     return step
@@ -1268,8 +1309,9 @@ def _tele_delivery_rows(final, j, telemetry, num_slices: int, t0=0,
     hist = jnp.zeros((num_slices, telemetry.num_buckets), jnp.int32).at[
         relc, bucket].add(jnp.where(ok, 1, 0))
     if axis is not None:
-        rows = jax.lax.psum(rows, axis)
-        hist = jax.lax.psum(hist, axis)
+        with jax.named_scope(tracing.EXCHANGE):
+            rows = jax.lax.psum(rows, axis)
+            hist = jax.lax.psum(hist, axis)
     return rows, hist
 
 
@@ -1289,8 +1331,9 @@ def _sim_out(final, ys, j=None, telemetry=None, num_slices=None, axis=None):
         if k in ys:
             out[k] = ys[k]
     if telemetry is not None:
-        rows, hist = _tele_delivery_rows(final, j, telemetry, num_slices,
-                                         axis=axis)
+        with jax.named_scope("fabric/finish"):
+            rows, hist = _tele_delivery_rows(final, j, telemetry, num_slices,
+                                             axis=axis)
         out["tele_delivered"] = rows
         out["tele_lat_hist"] = hist
     return out
@@ -1308,8 +1351,9 @@ def _sim_body(j, cfg: FabricConfig, num_slices: int, per_packet_mp: bool,
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
 def _simulate_jit(j, cfg: FabricConfig, num_slices: int, per_packet_mp: bool,
                   num_flows: int, telemetry: TelemetryConfig | None = None):
-    return _sim_body(j, cfg, num_slices, per_packet_mp, num_flows,
-                     telemetry=telemetry)
+    with tracing.retrace("_simulate_jit"):
+        return _sim_body(j, cfg, num_slices, per_packet_mp, num_flows,
+                         telemetry=telemetry)
 
 
 # ---------------------------------------------------------------------------
@@ -1343,7 +1387,9 @@ def _simulate_sharded_jit(j, cfg: FabricConfig, num_slices: int,
                                  jnp.arange(num_slices, dtype=jnp.int32))
         out = _sim_out(final, ys, jl, telemetry, num_slices, axis="tor")
         # reorder was carried as a per-shard partial count (see _make_step)
-        out["reorder_cnt"] = jax.lax.psum(out["reorder_cnt"], "tor")
+        with jax.named_scope("fabric/finish"), \
+                jax.named_scope(tracing.EXCHANGE):
+            out["reorder_cnt"] = jax.lax.psum(out["reorder_cnt"], "tor")
         out["adm_shard"] = final["adm_shard"]
         return out
 
@@ -1364,8 +1410,9 @@ def _simulate_sharded_jit(j, cfg: FabricConfig, num_slices: int,
     if telemetry is not None:
         # counter rows are psum-reconciled inside the step -> replicated
         out_specs.update({k: PS() for k in TELE_KEYS})
-    return jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
-                         out_specs=out_specs, check_vma=False)(j)
+    with tracing.retrace("_simulate_sharded_jit"):
+        return jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
+                             out_specs=out_specs, check_vma=False)(j)
 
 
 def _check_impls(cfg: FabricConfig):
@@ -1464,10 +1511,11 @@ def simulate_sharded(tables: FabricTables, wl: Workload, cfg: FabricConfig,
 def _simulate_fleet_jit(jb, cfg: FabricConfig, num_slices: int,
                         per_packet_mp: bool, num_flows: int,
                         telemetry: TelemetryConfig | None = None):
-    return jax.vmap(
-        lambda jj: _sim_body(jj, cfg, num_slices, per_packet_mp, num_flows,
-                             batched=True, telemetry=telemetry)
-    )(jb)
+    with tracing.retrace("_simulate_fleet_jit"):
+        return jax.vmap(
+            lambda jj: _sim_body(jj, cfg, num_slices, per_packet_mp,
+                                 num_flows, batched=True, telemetry=telemetry)
+        )(jb)
 
 
 def simulate_fleet(tables, wls, cfg: FabricConfig, num_slices: int,
@@ -1613,15 +1661,20 @@ class FabricState:
 def _window_jit(j, state, t0, cfg: FabricConfig, n_slices: int,
                 per_packet_mp: bool, num_flows: int,
                 telemetry: TelemetryConfig | None = None):
-    step = _make_step(j, cfg, per_packet_mp, num_flows, telemetry=telemetry)
-    final, ys = jax.lax.scan(step, state,
-                             t0 + jnp.arange(n_slices, dtype=jnp.int32))
-    if telemetry is not None:
-        # window-local delivery rows from the terminal state: deliveries
-        # from earlier windows fall outside [t0, t0 + n) and scatter nothing
-        rows, hist = _tele_delivery_rows(final, j, telemetry, n_slices, t0)
-        ys = dict(ys, tele_delivered=rows, tele_lat_hist=hist)
-    return final, ys
+    with tracing.retrace("_window_jit"):
+        step = _make_step(j, cfg, per_packet_mp, num_flows,
+                          telemetry=telemetry)
+        final, ys = jax.lax.scan(step, state,
+                                 t0 + jnp.arange(n_slices, dtype=jnp.int32))
+        if telemetry is not None:
+            # window-local delivery rows from the terminal state: deliveries
+            # from earlier windows fall outside [t0, t0 + n) and scatter
+            # nothing
+            with jax.named_scope("fabric/finish"):
+                rows, hist = _tele_delivery_rows(final, j, telemetry,
+                                                 n_slices, t0)
+            ys = dict(ys, tele_delivered=rows, tele_lat_hist=hist)
+        return final, ys
 
 
 def init_state(tables: FabricTables, wl: Workload | None, cfg: FabricConfig,
@@ -1719,10 +1772,14 @@ def step_slices(fs: FabricState, num_slices: int, failures=None,
     if failures is not None or control is not None:
         # window-local mask rows: _make_step re-bases mask lookups only
         jw["mask_t0"] = jnp.int32(fs.clock)
-    fs.state, ys = _window_jit(jw, fs.state, jnp.int32(fs.clock), fs.cfg,
-                               int(num_slices), fs.per_packet_mp,
-                               fs.num_flows, fs.telemetry)
-    fs.chunks.append({k: np.asarray(v) for k, v in ys.items()})
+    with tracing.span("advance.dispatch"):
+        fs.state, ys = _window_jit(jw, fs.state, jnp.int32(fs.clock), fs.cfg,
+                                   int(num_slices), fs.per_packet_mp,
+                                   fs.num_flows, fs.telemetry)
+    with tracing.span("advance.device_wait"):
+        ys = jax.block_until_ready(ys)
+    with tracing.span("advance.stats_copy"):
+        fs.chunks.append({k: np.asarray(v) for k, v in ys.items()})
     fs.clock += int(num_slices)
     return fs
 

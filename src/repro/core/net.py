@@ -25,6 +25,7 @@ import numpy as np
 
 from . import fabric as fabric_mod
 from . import routing as routing_mod
+from . import tracing
 from .controlplane import ControlTrace, compile_control
 from .fabric import (FabricConfig, FabricState, FabricTables, SimResult,
                      Workload, simulate)
@@ -188,31 +189,36 @@ class OpenOpticsNet:
 
     # -- Execution -------------------------------------------------------------
     def run(self, wl: Workload, num_slices: int) -> SimResult:
-        if self.schedule is None or self.routing is None:
-            raise RuntimeError("deploy_topo and deploy_routing first")
-        tables = FabricTables.build(self.schedule, self.routing)
-        masks = None
-        # only windows a fault can touch pay the failure branch — healed
-        # or not-yet-started traces keep the zero-failure fast path
-        if self.failure_trace.active_in(self._clock,
-                                        self._clock + num_slices):
-            masks = compile_masks(self.failure_trace, self.schedule,
-                                  num_slices, t0=self._clock)
-        ctrl = None
-        if self.control_trace.active_in(self._clock,
-                                        self._clock + num_slices):
-            ctrl = compile_control(
-                self.control_trace, num_slices, self.n_nodes,
-                slice_ns=self.slice_us * 1000.0, t0=self._clock)
-        res = simulate(tables, wl, self.fabric_cfg, num_slices,
-                       failures=masks, control=ctrl)
-        self._last_result = res
-        self._last_workload = wl
-        tm = np.zeros((self.n_nodes, self.n_nodes), dtype=np.float64)
-        np.add.at(tm, (wl.src, wl.dst), wl.size.astype(np.float64))
-        self._last_tm = tm
-        self._clock += num_slices
-        return res
+        with tracing.span("OpenOpticsNet.run"):
+            if self.schedule is None or self.routing is None:
+                raise RuntimeError("deploy_topo and deploy_routing first")
+            with tracing.span("run.tables"):
+                tables = FabricTables.build(self.schedule, self.routing)
+            masks = None
+            # only windows a fault can touch pay the failure branch — healed
+            # or not-yet-started traces keep the zero-failure fast path
+            if self.failure_trace.active_in(self._clock,
+                                            self._clock + num_slices):
+                with tracing.span("run.masks"):
+                    masks = compile_masks(self.failure_trace, self.schedule,
+                                          num_slices, t0=self._clock)
+            ctrl = None
+            if self.control_trace.active_in(self._clock,
+                                            self._clock + num_slices):
+                with tracing.span("run.masks"):
+                    ctrl = compile_control(
+                        self.control_trace, num_slices, self.n_nodes,
+                        slice_ns=self.slice_us * 1000.0, t0=self._clock)
+            res = simulate(tables, wl, self.fabric_cfg, num_slices,
+                           failures=masks, control=ctrl)
+            self._last_result = res
+            self._last_workload = wl
+            with tracing.span("run.traffic_matrix"):
+                tm = np.zeros((self.n_nodes, self.n_nodes), dtype=np.float64)
+                np.add.at(tm, (wl.src, wl.dst), wl.size.astype(np.float64))
+            self._last_tm = tm
+            self._clock += num_slices
+            return res
 
     # -- Clocked service (ISSUE 8: long-lived incremental fabric) -------------
     def _service_state(self) -> FabricState:
@@ -234,14 +240,16 @@ class OpenOpticsNet:
         sequences. Growing the packet population re-traces the window
         program, so batch ingests beat per-packet ones.
         """
-        fs = self._service_state()
-        if wl.num_packets == 0:
+        with tracing.span("OpenOpticsNet.ingest"):
+            fs = self._service_state()
+            if wl.num_packets == 0:
+                return True
+            wl = dataclasses.replace(
+                wl, t_inject=wl.t_inject + np.int32(self._clock),
+                flow=wl.flow + np.int32(fs.num_flows))
+            with tracing.span("ingest.concat"):
+                fabric_mod.ingest(fs, wl)
             return True
-        wl = dataclasses.replace(
-            wl, t_inject=wl.t_inject + np.int32(self._clock),
-            flow=wl.flow + np.int32(fs.num_flows))
-        fabric_mod.ingest(fs, wl)
-        return True
 
     def advance(self, num_slices: int) -> bool:
         """Advance the live fabric ``num_slices`` slices (one jitted window
@@ -251,21 +259,23 @@ class OpenOpticsNet:
         State (packets in flight, queue occupancy, telemetry counters)
         carries across calls; :meth:`snapshot` reads it without stopping.
         """
-        fs = self._service_state()
-        n = int(num_slices)
-        if n <= 0:
-            raise ValueError(f"num_slices must be positive, got {num_slices}")
-        masks = ctrl = None
-        if self.failure_trace.active_in(self._clock, self._clock + n):
-            masks = compile_masks(self.failure_trace, self.schedule, n,
-                                  t0=self._clock)
-        if self.control_trace.active_in(self._clock, self._clock + n):
-            ctrl = compile_control(
-                self.control_trace, n, self.n_nodes,
-                slice_ns=self.slice_us * 1000.0, t0=self._clock)
-        fabric_mod.step_slices(fs, n, failures=masks, control=ctrl)
-        self._clock = fs.clock
-        return True
+        with tracing.span("OpenOpticsNet.advance"):
+            fs = self._service_state()
+            n = int(num_slices)
+            if n <= 0:
+                raise ValueError(
+                    f"num_slices must be positive, got {num_slices}")
+            masks = ctrl = None
+            if self.failure_trace.active_in(self._clock, self._clock + n):
+                masks = compile_masks(self.failure_trace, self.schedule, n,
+                                      t0=self._clock)
+            if self.control_trace.active_in(self._clock, self._clock + n):
+                ctrl = compile_control(
+                    self.control_trace, n, self.n_nodes,
+                    slice_ns=self.slice_us * 1000.0, t0=self._clock)
+            fabric_mod.step_slices(fs, n, failures=masks, control=ctrl)
+            self._clock = fs.clock
+            return True
 
     def snapshot(self) -> dict:
         """Host-side structured telemetry frame of the live fabric, without
@@ -275,44 +285,45 @@ class OpenOpticsNet:
         delivery-latency histogram. ``in_flight`` includes electrical
         deliveries still in transit past the clock; ``pending`` packets
         have not injected yet."""
-        fs = self._service
-        frame = {"clock": self._clock,
-                 "packets": {}, "bytes": {}, "counters": None}
-        if fs is None:
-            zero = dict(total=0, pending=0, in_flight=0, delivered=0,
-                        dropped=0)
-            frame["packets"] = dict(zero)
-            frame["bytes"] = dict(zero)
+        with tracing.span("OpenOpticsNet.snapshot"):
+            fs = self._service
+            frame = {"clock": self._clock,
+                     "packets": {}, "bytes": {}, "counters": None}
+            if fs is None:
+                zero = dict(total=0, pending=0, in_flight=0, delivered=0,
+                            dropped=0)
+                frame["packets"] = dict(zero)
+                frame["bytes"] = dict(zero)
+                return frame
+            loc = np.asarray(fs.state["loc"])
+            t_del = np.asarray(fs.state["t_del"])
+            size = np.asarray(fs.j["size"]).astype(np.int64)
+            NI, DL, DR = (fabric_mod.NOT_INJECTED, fabric_mod.DELIVERED,
+                          fabric_mod.DROPPED)
+            groups = dict(
+                pending=loc == NI,
+                in_flight=(loc >= 0) | ((loc == DL) & (t_del >= fs.clock)),
+                delivered=(loc == DL) & (t_del < fs.clock),
+                dropped=loc == DR)
+            frame["packets"] = {"total": int(loc.size)} | {
+                k: int(m.sum()) for k, m in groups.items()}
+            frame["bytes"] = {"total": int(size.sum())} | {
+                k: int(size[m].sum()) for k, m in groups.items()}
+            if fs.telemetry is not None and fs.chunks:
+                rows = {k: np.concatenate([c[k] for c in fs.chunks])
+                        for k in TELE_KEYS}
+                frame["counters"] = {
+                    "injected_bytes": rows["tele_injected"].sum(0),
+                    "delivered_bytes": rows["tele_delivered"].sum(0),
+                    "deferred_bytes": rows["tele_deferred"].sum(0),
+                    "dropped_bytes": rows["tele_dropped"].sum(0),
+                    "queue_hwm": rows["tele_qhwm"].max(0),
+                    "util_used": rows["tele_util_used"].sum(0),
+                    "util_cap": rows["tele_util_cap"].sum(0),
+                    "lat_hist": rows["tele_lat_hist"].sum(0),
+                    "lat_edges": fs.telemetry.lat_edges,
+                }
             return frame
-        loc = np.asarray(fs.state["loc"])
-        t_del = np.asarray(fs.state["t_del"])
-        size = np.asarray(fs.j["size"]).astype(np.int64)
-        NI, DL, DR = (fabric_mod.NOT_INJECTED, fabric_mod.DELIVERED,
-                      fabric_mod.DROPPED)
-        groups = dict(
-            pending=loc == NI,
-            in_flight=(loc >= 0) | ((loc == DL) & (t_del >= fs.clock)),
-            delivered=(loc == DL) & (t_del < fs.clock),
-            dropped=loc == DR)
-        frame["packets"] = {"total": int(loc.size)} | {
-            k: int(m.sum()) for k, m in groups.items()}
-        frame["bytes"] = {"total": int(size.sum())} | {
-            k: int(size[m].sum()) for k, m in groups.items()}
-        if fs.telemetry is not None and fs.chunks:
-            rows = {k: np.concatenate([c[k] for c in fs.chunks])
-                    for k in TELE_KEYS}
-            frame["counters"] = {
-                "injected_bytes": rows["tele_injected"].sum(0),
-                "delivered_bytes": rows["tele_delivered"].sum(0),
-                "deferred_bytes": rows["tele_deferred"].sum(0),
-                "dropped_bytes": rows["tele_dropped"].sum(0),
-                "queue_hwm": rows["tele_qhwm"].max(0),
-                "util_used": rows["tele_util_used"].sum(0),
-                "util_cap": rows["tele_util_cap"].sum(0),
-                "lat_hist": rows["tele_lat_hist"].sum(0),
-                "lat_edges": fs.telemetry.lat_edges,
-            }
-        return frame
 
     def service_result(self) -> SimResult:
         """Checkpoint the live fabric as a :class:`SimResult` (the service

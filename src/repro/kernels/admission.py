@@ -176,5 +176,6 @@ def admission_admit(key, size, want, cap_left, *, num_keys: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="group_admit",
     )(cap, key, size)
     return adm[0, :P].astype(bool), used.T.reshape(-1)[:num_keys]

@@ -114,6 +114,7 @@ def time_flow_lookup(tbl_next, tbl_dep, node, dst, hashv, *, bp: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="tf_lookup",
     )(_stack_tables(tbl_next, tbl_dep, Dp, Np), row(node), row(dst),
       row(hashv))
     return nxt[0, :P], dep[0, :P]
