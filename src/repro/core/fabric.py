@@ -51,7 +51,11 @@ kept in ``tests/fabric_ref.py``):
   rejected index of its group can never be admitted in a later hop. Hop 0
   records the minimum rejected index per group; hops >= 1 only re-sort the
   cut-through continuations. This is what makes the packet vector
-  effectively *sorted once per slice*. Under push-back the capacity
+  effectively *sorted once per slice*. The cuts start every slice empty,
+  so the filter is the identity until a group (or, under push-back, a
+  receiver) holds one: hop 0 never reads them, and a later hop gathers
+  each packet's cut (scope ``fabric/hop/backlog_filter/gather``) only
+  under a ``lax.cond`` on "any cut held". Under push-back the capacity
   argument is weakened (an rx candidate that later flips to rx-rejected
   removes its bytes from successors' capacity prefixes), but two rx-aware
   cuts survive and are applied instead. Receivers' rx rejections are
@@ -1148,30 +1152,45 @@ def _make_step(j, cfg: FabricConfig, per_packet_mp: bool, num_flows: int,
         backlog_min = jnp.full((NKEY,), PG, jnp.int32)
         rx_backlog_min = jnp.full((N,), PG, jnp.int32)
         resc_min = jnp.full((NKEY,), PG, jnp.int32)
+        def backlog_cut(want0, s, backlog_min, rx_backlog_min):
+            with jax.named_scope("gather"):
+                key_all = jnp.clip(s["loc"], 0, N - 1) * (N + 1) + \
+                    jnp.clip(s["nxt"], 0, N)
+                if not cfg.pushback:
+                    return want0 & (pid < backlog_min[key_all])
+                # push-back-aware backlog filter: drop candidates at-or-after
+                # a receiver's first rx-rejected index (rx rejection is
+                # monotone — see hop_logic), and rx-exempt candidates
+                # strictly *after* their group's first marked capacity
+                # rejection (the marked packet itself stays in the sort as
+                # the byte anchor of every successor's over-capacity
+                # prefix). rx-subject capacity rejections stay unfiltered:
+                # their prefixes can lose bytes to later rx flips, and
+                # their bytes feed other candidates' rx prefixes.
+                rx_subject = (s["nxt"] >= 0) & (s["nxt"] < N) & \
+                    (s["nxt"] != j["dst"])
+                want0 &= ~(rx_subject &
+                           (pid >= rx_backlog_min[jnp.clip(s["nxt"], 0, N - 1)]))
+                return want0 & ~(~rx_subject & (pid > backlog_min[key_all]))
+
         for _hop in range(cfg.hops_per_slice):
             with jax.named_scope("hop"):
                 with jax.named_scope("backlog_filter"):
                     want0 = (s["loc"] >= 0) & (s["dep"] == t) & (s["nxt"] >= 0) & \
                             (s["nhops"] < cfg.max_hops)
-                    key_all = jnp.clip(s["loc"], 0, N - 1) * (N + 1) + \
-                        jnp.clip(s["nxt"], 0, N)
-                    if not cfg.pushback:
-                        want0 &= pid < backlog_min[key_all]
-                    else:
-                        # push-back-aware backlog filter: drop candidates at-or-after
-                        # a receiver's first rx-rejected index (rx rejection is
-                        # monotone — see hop_logic), and rx-exempt candidates
-                        # strictly *after* their group's first marked capacity
-                        # rejection (the marked packet itself stays in the sort as
-                        # the byte anchor of every successor's over-capacity
-                        # prefix). rx-subject capacity rejections stay unfiltered:
-                        # their prefixes can lose bytes to later rx flips, and
-                        # their bytes feed other candidates' rx prefixes.
-                        rx_subject = (s["nxt"] >= 0) & (s["nxt"] < N) & \
-                            (s["nxt"] != j["dst"])
-                        want0 &= ~(rx_subject &
-                                   (pid >= rx_backlog_min[jnp.clip(s["nxt"], 0, N - 1)]))
-                        want0 &= ~(~rx_subject & (pid > backlog_min[key_all]))
+                    if _hop > 0:
+                        # every cut starts the slice at PG, where the filter
+                        # is the identity (hop 0 reads none); gather them only
+                        # once some group or receiver holds one. The cuts are
+                        # exchanged at the end of every hop, so all shards
+                        # take the same branch.
+                        engaged = jnp.any(backlog_min < PG)
+                        if cfg.pushback:
+                            engaged |= jnp.any(rx_backlog_min < PG)
+                        want0 = jax.lax.cond(
+                            engaged, backlog_cut, lambda w, *_: w, want0,
+                            {k: s[k] for k in ("loc", "nxt")}, backlog_min,
+                            rx_backlog_min)
                 cnt0 = jnp.sum(want0)
 
                 def hop_full(carry, want0=want0):

@@ -21,6 +21,14 @@ on or sharded, and the compact views (``compact``, ``scatter_back``) only
 in a single-device, unbatched program with more packets than the smallest
 view (2,048).
 
+:data:`BACKLOG_GATHER` is not a phase but a counter, nested in
+``fabric/hop/backlog_filter``: the gather of each packet's backlog cut,
+which runs inside a conditional branch, in the hops after the first where
+some group holds a cut (so only with more than one hop per slice). Its
+executions over slices x (hops - 1) are the share of hops the filter
+engaged. A reducer that knows only the names of :data:`SCOPES` counts its
+time in ``fabric/hop/backlog_filter``.
+
 Host spans (names in ``docs/api/core.tracing.md``): ``OpenOpticsNet.run``
 around the whole call, with ``run.tables``, ``run.masks``,
 ``run.to_device``, ``run.dispatch``, ``run.device_wait``,
@@ -38,7 +46,8 @@ import collections
 
 import jax
 
-__all__ = ["SCOPES", "EXCHANGE", "retraces", "span", "retrace"]
+__all__ = ["SCOPES", "BACKLOG_GATHER", "EXCHANGE", "retraces", "span",
+           "retrace"]
 
 SCOPES = (
     "fabric/activate",                 # phase 0: activating queues leave occ
@@ -59,6 +68,7 @@ SCOPES = (
     "fabric/stats",                    # phase 5 and the telemetry rows
     "fabric/finish",                   # the result after the scan
 )
+BACKLOG_GATHER = "fabric/hop/backlog_filter/gather"
 EXCHANGE = "exchange"
 
 # traces of each jitted entry point's Python body, which runs only when JAX

@@ -71,23 +71,37 @@ def test_simulate_bit_identical_rotor_single_hop():
                           simulate_ref(tables, wl, cfg, SLICES))
 
 
-@pytest.mark.parametrize("over", [
-    dict(),  # backlog-filter + tiered compact views, plain cc_detect
-    dict(pushback=True, offload=True, offload_horizon=1,
-         switch_buffer=200_000),
-], ids=["plain", "pushback-offload"])
-def test_simulate_bit_identical_large_population(over):
+@pytest.mark.parametrize("over,gate", [
+    # 4x overload: the backlog gate opens at hops >= 1 in every slice
+    (dict(), "open"),  # backlog-filter + tiered compact views, plain cc_detect
+    (dict(pushback=True, offload=True, offload_horizon=1,
+          switch_buffer=200_000), "open"),
+    # 0.4x: a few slices reject, so the gate opens at hops >= 1 in those
+    # alone; UCMP's multi-hop candidates reach hop 1 in the others too
+    (dict(slice_bytes=400_000), "some"),
+    (dict(slice_bytes=400_000, pushback=True), "some"),
+    # 0.2x: no rejection all run, so the gate stays shut at every hop
+    (dict(slice_bytes=800_000), "shut"),
+    (dict(slice_bytes=800_000, pushback=True), "shut"),
+], ids=["plain", "pushback-offload", "light", "light-pushback", "gate-shut",
+        "gate-shut-pushback"])
+def test_simulate_bit_identical_large_population(over, gate):
     """P > the compact-view tier bounds, so the tiered compact/full dispatch
-    (including spill to the full-width path) is exercised."""
+    (including spill to the full-width path) is exercised, with the backlog
+    gate open in every slice, in some, and in none."""
     import repro.core.fabric as fabric
     assert fabric.SMALL_C < 9000 < fabric.ADMIT_C + 1000
     wl = synthesize("rpc", N, 12, slice_bytes=40_000, load=4.0,
                     max_packets=9000, seed=13)
     assert wl.num_packets > fabric.SMALL_C
     tables = _tables()
-    cfg = FabricConfig(slice_bytes=40_000, **over)
-    _assert_results_equal(simulate(tables, wl, cfg, 20),
-                          simulate_ref(tables, wl, cfg, 20))
+    cfg = FabricConfig(**{"slice_bytes": 40_000, **over})
+    got = simulate(tables, wl, cfg, 20)
+    _assert_results_equal(got, simulate_ref(tables, wl, cfg, 20))
+    # a rejection misses its slice, and only a rejection opens the gate
+    rejecting = int((got.slice_miss > 0).sum())
+    assert {"open": rejecting > 0, "some": 0 < rejecting < 20,
+            "shut": rejecting == 0}[gate]
 
 
 def test_simulate_bit_identical_mixed_rx_capacity_pressure():

@@ -61,6 +61,7 @@ def _diff(tables, wl, cfg, num_shards, failures=None, control=None):
                                 control=control, with_debug=True)
     _assert_results_equal(got, ref)
     assert toolkit.check_sharding(got, dbg, wl, SLICES) == []
+    return ref
 
 
 @pytest.mark.parametrize("alg", SCHEMES, ids=lambda a: a.__name__)
@@ -103,6 +104,23 @@ def test_mechanism_matrix_bit_identical(over, eight_devices):
     hybrid electrical egress + push-back under buffer pressure)."""
     _diff(_tables(vlb), _workload(), FabricConfig(slice_bytes=4_000, **over),
           4)
+
+
+@pytest.mark.parametrize("pushback", [False, True],
+                         ids=["pushback_off", "pushback_on"])
+@pytest.mark.parametrize("slice_bytes,shut", [(20_000, True), (4_000, False)],
+                         ids=["gate-shut", "gate-open"])
+def test_backlog_gate_bit_identical(slice_bytes, shut, pushback,
+                                    eight_devices):
+    """UCMP multi-hop under the backlog filter's gate, shut all run (no
+    rejection on 20 KB circuits) and open at hops >= 1 (4 KB circuits):
+    the cuts are exchanged before the gate reads them, so every shard
+    takes the same branch."""
+    cfg = FabricConfig(slice_bytes=slice_bytes, cc_detect=True,
+                       pushback=pushback)
+    ref = _diff(_tables(ucmp), _workload(seed=0), cfg, 4)
+    # a rejection misses its slice, and only a rejection opens the gate
+    assert (ref.slice_miss.sum() == 0) == shut
 
 
 @pytest.mark.parametrize("impls", [

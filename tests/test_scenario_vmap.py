@@ -59,6 +59,27 @@ def test_fleet_seed_sweep_bit_identical():
                               f"seed {i}: ")
 
 
+@pytest.mark.parametrize("pushback", [False, True],
+                         ids=["pushback_off", "pushback_on"])
+@pytest.mark.parametrize("slice_bytes,shut", [(20_000, True), (4_000, False)],
+                         ids=["gate-shut", "gate-open"])
+def test_fleet_backlog_gate_bit_identical(slice_bytes, shut, pushback):
+    """UCMP multi-hop under the backlog filter's gate, shut all run (no
+    rejection on 20 KB circuits) and open at hops >= 1 (4 KB circuits):
+    batched, the gate's cond runs both branches behind a select."""
+    sched = round_robin(N, 1)
+    tables = FabricTables.build(sched, ucmp(sched))
+    cfg = FabricConfig(slice_bytes=slice_bytes, cc_detect=True,
+                       pushback=pushback)
+    wls = [_wl(s) for s in range(3)]
+    gots = simulate_fleet(tables, wls, cfg, SLICES)
+    for i, (wl, got) in enumerate(zip(wls, gots)):
+        ref = simulate(tables, wl, cfg, SLICES)
+        _assert_results_equal(got, ref, f"seed {i}: ")
+        # a rejection misses its slice, and only a rejection opens the gate
+        assert (ref.slice_miss.sum() == 0) == shut, f"seed {i}"
+
+
 def test_fleet_failure_trace_sweep_bit_identical():
     """Failover sweep: one workload, 4 seeded failure traces (+ control
     faults), batched over the mask tensors."""

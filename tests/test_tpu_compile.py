@@ -18,9 +18,11 @@ import jax.numpy as jnp
 
 from repro.core import (FabricConfig, FabricTables, round_robin, synthesize,
                         ucmp)
-from repro.core import fabric
+from repro.core import fabric, tracing
 from repro.kernels.admission import admission_admit
 from repro.kernels.time_flow_lookup import time_flow_lookup
+
+import hlo_text
 
 PAPER_TORS = 108
 P = 1 << 15
@@ -64,8 +66,8 @@ def test_admission_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in txt
 
 
-def test_simulate_with_pallas_backends_compiles_for_v5e(one_chip):
-    """Both kernels lower inside the fabric's per-slice scan."""
+def _compiled_scan_text(one_chip, cfg):
+    """The fabric scan at 16 ToRs and 4,096 packets, compiled for a v5e."""
     n = 16
     sched = round_robin(n, 1, slice_us=10.0)
     tables = FabricTables.build(sched, ucmp(sched))
@@ -79,8 +81,27 @@ def test_simulate_with_pallas_backends_compiles_for_v5e(one_chip):
     shapes = {k: jax.ShapeDtypeStruct(
         np.shape(v), jnp.bool_ if k == "is_eleph" else jnp.int32,
         sharding=one_chip) for k, v in j.items()}
+    return fabric._simulate_jit.lower(shapes, cfg, 48, True, wl.num_flows,
+                                      None).compile().as_text()
+
+
+def test_simulate_with_pallas_backends_compiles_for_v5e(one_chip):
+    """Both kernels lower inside the fabric's per-slice scan."""
     cfg = FabricConfig(slice_bytes=125_000, lookup_impl="pallas",
                        admit_impl="pallas")
-    txt = fabric._simulate_jit.lower(shapes, cfg, 48, True, wl.num_flows,
-                                     None).compile().as_text()
-    assert "tpu_custom_call" in txt
+    assert "tpu_custom_call" in _compiled_scan_text(one_chip, cfg)
+
+
+@pytest.mark.parametrize("pushback", [False, True],
+                         ids=["pushback_off", "pushback_on"])
+def test_backlog_gather_stays_in_a_conditional_for_v5e(one_chip, pushback):
+    """The TPU compiler keeps the backlog filter's gather in a conditional
+    branch of its own at each hop after the first, and turns none of them
+    into a select that would run it every hop."""
+    cfg = FabricConfig(slice_bytes=125_000, cc_detect=True, pushback=pushback)
+    words = {w for s in tracing.SCOPES for w in s.split("/")} | {"gather"}
+    found = hlo_text.scoped_gathers(_compiled_scan_text(one_chip, cfg), words)
+    gated = [b for path, b in found if path == tracing.BACKLOG_GATHER]
+    assert gated and all(gated)
+    assert len(set(gated)) == cfg.hops_per_slice - 1
+    assert not [p for p, _ in found if p == "fabric/hop/backlog_filter"]

@@ -16,6 +16,8 @@ from repro.core.fabric import Workload
 from repro.core.telemetry import TelemetryConfig
 from repro.distributed import sharding as dshard
 
+import hlo_text
+
 N = 8
 SLICES = 16
 # above the smallest compact view (2,048), so the compacted phases exist
@@ -105,6 +107,28 @@ def test_lowered_programs_carry_every_scope(program, tele):
         # without telemetry the result is the scan's own output: no op
         want.discard("fabric/finish")
     assert found == want
+
+
+@pytest.mark.parametrize("pushback,hops,per_hop", [
+    (False, 4, 1),     # the group cut
+    (True, 4, 2),      # the group cut and the receiver cut
+    (False, 1, 0),     # hop 0 alone reads no cut
+], ids=["pushback_off", "pushback_on", "one_hop"])
+def test_backlog_gather_only_in_a_cond_from_hop_one(pushback, hops, per_hop):
+    """The backlog filter gathers the cuts only under its ``gather``
+    scope, inside a cond branch of its own at each hop after the first:
+    hop 0 has no gather, and no gather of the filter runs unconditionally."""
+    cfg = FabricConfig(slice_bytes=12_000, cc_detect=True, pushback=pushback,
+                       hops_per_slice=hops)
+    lowered = fabric._simulate_jit.lower(_device_inputs(_workload()), cfg,
+                                         SLICES, True, P, None)
+    found = hlo_text.scoped_gathers(
+        lowered.as_text(dialect="hlo", debug_info=True),
+        WORDS | {"gather"})
+    gated = [b for path, b in found if path == tracing.BACKLOG_GATHER]
+    assert len(gated) == per_hop * (hops - 1)
+    assert all(gated) and len(set(gated)) == hops - 1
+    assert not [p for p, _ in found if p == "fabric/hop/backlog_filter"]
 
 
 def _host_events(log_dir):
