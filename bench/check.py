@@ -18,9 +18,10 @@ routes looked up in the previous slice's time-flow table, which breaks a
 guarantee the deployment states; it has to fail.
 
 The reference's results are kept in ``.bench_cache/`` inside the checkout,
-keyed by a hash of the reference's code and of everything it is given, so
-a later run on the same seed (the second set of a measurement) reads them
-back instead of running the reference again.
+keyed by a hash of the reference's code (every ``.py`` under
+``bench/reference/``) and of everything it is given, so a later run on the
+same seed (the second set of a measurement) reads them back instead of
+running the reference again.
 """
 from __future__ import annotations
 
@@ -97,10 +98,19 @@ def run_reference(deployment: dict, ref_tables: dict, workloads: dict,
             for i, wl in workloads.items()}
 
 
-def _cached_reference(tables: dict, wl: dict, cfg, num_slices: int) -> dict:
+def reference_code() -> bytes:
+    """A hash of every ``.py`` under ``bench/reference/``, at any depth, with
+    its path, so that a new or changed schedule or scheme file changes the
+    key of every cached result."""
     key = hashlib.sha256()
-    for f in sorted(pathlib.Path(fabric_ref.__file__).parent.glob("*.py")):
+    for f in sorted(tables_ref.REFERENCE.rglob("*.py")):
+        key.update(f.relative_to(tables_ref.REFERENCE).as_posix().encode())
         key.update(f.read_bytes())
+    return key.digest()
+
+
+def _cached_reference(tables: dict, wl: dict, cfg, num_slices: int) -> dict:
+    key = hashlib.sha256(reference_code())
     key.update(repr((cfg, num_slices)).encode())
     for name, a in sorted({**tables, **wl}.items()):
         a = np.ascontiguousarray(a)

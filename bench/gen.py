@@ -19,6 +19,13 @@ argument of its jitted scan). The seed of :func:`workload` relabels the
 ToRs (a permutation, so every ToR keeps one stream group's load) and draws
 every flow's destination.
 
+A run's workloads (:func:`pool`) are the same for every run seed up to
+the names of the ToRs: they are drawn from the mix's ``size_seed``, and
+the run seed turns each by a cyclic shift of the ToR labels. A
+round-robin schedule maps onto itself under such a shift, so every seed
+gives the fabric the same work, packet for packet, and the window's rate
+does not depend on the seed the run drew.
+
 Distributions, each a dict with one of (MATLAB's parameterisation, as
 the source papers fit them):
 
@@ -112,8 +119,9 @@ def population(deployment: dict, mix: dict):
                 is_eleph=size[:F] >= mix["elephant_bytes"])
 
 
-def workload(deployment: dict, mix: dict, seed: int, pop=None) -> dict:
-    """One workload of the fixed population for ``seed``."""
+def workload(deployment: dict, mix: dict, seed, pop=None) -> dict:
+    """One workload of the fixed population for ``seed`` (whatever
+    ``np.random.default_rng`` takes)."""
     pop = population(deployment, mix) if pop is None else pop
     n_nodes = deployment["tors"]
     cell = mix["cell_bytes"]
@@ -133,3 +141,20 @@ def workload(deployment: dict, mix: dict, seed: int, pop=None) -> dict:
                 t_inject=i32(pop["t_start"][flow] + seq // per_slice),
                 flow=i32(flow), seq=i32(seq),
                 is_eleph=np.ascontiguousarray(pop["is_eleph"][flow]))
+
+
+def pool(deployment: dict, mix: dict, seed: int, size: int,
+         pop=None) -> list[dict]:
+    """A run's ``size`` workloads for ``seed``: workload k is drawn from
+    ``(size_seed, k)``, the same for every seed, and its ToR labels are
+    turned by a shift drawn from ``seed`` (node i becomes i + c mod N)."""
+    pop = population(deployment, mix) if pop is None else pop
+    n_nodes = deployment["tors"]
+    shifts = np.random.default_rng(seed).integers(0, n_nodes, size)
+    out = []
+    for k, c in enumerate(shifts):
+        wl = workload(deployment, mix, [mix["size_seed"], k], pop)
+        for f in ("src", "dst"):
+            wl[f] = ((wl[f] + c) % n_nodes).astype(np.int32)
+        out.append(wl)
+    return out

@@ -17,7 +17,11 @@ metric sits in a file of its own and is found by name:
   ``result`` also has ``control_outputs(ctl, wl, mix)``, the same form
   built from a reference result, for ``bench/control.py``;
 * ``bench/metrics/<metric>.py``: ``read(ctx)`` returns the per-layer
-  metric's value, or ``None`` where the run has nothing to read.
+  metric's value, or ``None`` where the run has nothing to read;
+* ``bench/reference/schedules/<schedule>.py`` and
+  ``bench/reference/schemes/<scheme>.py``: the plain reference of the
+  deployment's optical schedule and routing scheme, named by its config
+  (found by :func:`bench.reference.tables_ref.deployment_tables`).
 """
 from __future__ import annotations
 
@@ -137,9 +141,7 @@ class Harness:
         h = cls(cell=cell, deployment=dep, mix=mix, seed=seed,
                 chips=int(cell["chips"]), spans=Spans(),
                 clock=CompileClock())
-        pop = gen.population(dep, mix)
-        base = np.random.default_rng(seed).integers(0, 2**63 - 1, POOL)
-        h.workloads = [gen.workload(dep, mix, int(s), pop) for s in base]
+        h.workloads = gen.pool(dep, mix, seed, POOL)
         return h
 
     @property

@@ -2,8 +2,12 @@
 episode: a fresh net with telemetry on, one ``ingest(wl)``, then
 ``steps`` service steps of ``advance(window_slices)`` plus ``snapshot()``,
 then ``service_result()``. A service step is timed on the caller's side;
-``service_step_ms_p95`` is the 95th percentile over every step of the
-window.
+``service_step_ms_p98`` is the 98th percentile over every step of the
+window. The two steps of an episode that inject its packets are by far
+its slowest (on a TPU v5e 0.60 s and 0.44 s, the others 0.02-0.08 s).
+They are 2 of its 40 steps, so the 95th percentile falls on the edge
+between them and the rest, and one slow step elsewhere moves it across;
+the 98th lies among the first steps, with ten or more steps beyond it.
 
 The check compares each checked episode's ``service_result`` with the
 reference, bit for bit; its telemetry counters with the host replay of
@@ -66,7 +70,7 @@ class Driver:
 
     def end_to_end(self) -> dict:
         ms = 1e3 * np.asarray(self.step_s)
-        return {"service_step_ms_p95": float(np.percentile(ms, 95))}
+        return {"service_step_ms_p98": float(np.percentile(ms, 98))}
 
     def close(self):
         pass

@@ -90,3 +90,47 @@ def test_offered_load_is_the_mixs(mix, pop):
     assert np.abs(whole - mix["load"]).max() < 0.03
     assert gen.streams_per_tor(DEP, mix) == 260
     assert 300 < pop["size"].mean() < 430
+
+
+def test_seeds_share_the_pool_up_to_a_shift(mix, pop):
+    """Every seed's pool is the same workloads under cyclically shifted ToR
+    labels, one shift per workload."""
+    a = gen.pool(DEP, mix, 0, 4, pop)
+    b = gen.pool(DEP, mix, SEED, 4, pop)
+    n = DEP["tors"]
+    shifts = []
+    for wa, wb in zip(a, b):
+        for k in set(gen.FIELDS) - {"src", "dst"}:
+            np.testing.assert_array_equal(wa[k], wb[k], err_msg=k)
+        c = (int(wb["src"][0]) - int(wa["src"][0])) % n
+        for k in ("src", "dst"):
+            assert wb[k].dtype == np.int32
+            np.testing.assert_array_equal((wa[k] + c) % n, wb[k], err_msg=k)
+        shifts.append(c)
+    assert len(set(shifts)) > 1
+    assert not np.array_equal(a[0]["dst"], a[1]["dst"])
+
+
+@pytest.mark.parametrize("config", ["rotor108_vlb", "rotor108_ucmp"])
+def test_a_shift_leaves_the_work_unchanged(config):
+    """On the round-robin schedule the reference fabric gives every packet
+    the same delivery slice, hops and losses under a shift of the ToR
+    labels, and the per-slice totals are equal: a seed changes the names,
+    not the work."""
+    from bench import check
+    from bench.reference import fabric_ref, tables_ref
+    dep = harness.load_json("configs", config)
+    dep.update(tors=8, packets=2048)
+    mix = dict(harness.load_json("traffic", "kv_run"), num_slices=40)
+    tables = tables_ref.deployment_tables(dep)
+    cfg = check.reference_config(dep)
+    a, b = gen.pool(dep, mix, 3, 1)[0], gen.pool(dep, mix, 4, 1)[0]
+    assert not np.array_equal(a["src"], b["src"])
+    ra = fabric_ref.simulate_ref(tables, a, cfg, 40)
+    rb = fabric_ref.simulate_ref(tables, b, cfg, 40)
+    for f in fabric_ref.RESULT_FIELDS:
+        x, y = np.asarray(ra[f]), np.asarray(rb[f])
+        if f in ("buf_bytes", "offl_bytes"):        # per node: relabelled
+            x, y = np.sort(x, axis=-1), np.sort(y, axis=-1)
+        np.testing.assert_array_equal(x, y, err_msg=f)
+    assert (np.asarray(ra["t_deliver"]) >= 0).all()

@@ -16,11 +16,13 @@ BY_HAND = {
     "vlb_kv_run": dict(window_s=0.054368536, busy_s=0.032011978516,
                        admit_sort_share=0.9570765575981746,
                        idle_share=41.12039633364415,
-                       idle_metric="device_idle_share.run"),
+                       idle_metric="device_idle_share.run",
+                       slice_metric="device_ms_per_slice"),
     "vlb_kv_service": dict(window_s=0.216394803, busy_s=0.035421899926,
                            admit_sort_share=0.8650591601245099,
                            idle_share=83.6308915764488,
-                           idle_metric="device_idle_share.service"),
+                           idle_metric="device_idle_share.service",
+                           slice_metric="device_ms_per_slice.service"),
 }
 
 
@@ -62,7 +64,7 @@ def test_metrics_as_recorded(recorded):
         want["admit_sort_share"], rel=1e-4)
     assert read(want["idle_metric"]) == pytest.approx(want["idle_share"],
                                                       rel=1e-4)
-    assert read("device_ms_per_slice") == pytest.approx(
+    assert read(want["slice_metric"]) == pytest.approx(
         1e3 * want["busy_s"] / slices, rel=1e-4)
 
 

@@ -43,10 +43,8 @@ def spec(cell: str | None = None) -> dict:
 # cells whose files are in bench/ but which BENCHMARK.json does not hold
 # yet (PERF.md, Open questions)
 LATER = {c["name"]: c for c in (
-    {"name": "vlb_kv_service", "config": "rotor108_vlb",
-     "traffic": "kv_service", "chips": 1},
     {"name": "vlb_kv_shard4", "config": "rotor108_vlb",
-     "traffic": "kv_shard4", "chips": 4})}
+     "traffic": "kv_shard4", "chips": 4},)}
 
 
 def run_cell(cell: str, seed: int = SEED, seconds: float = 0.2,
